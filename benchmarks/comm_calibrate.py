@@ -47,7 +47,6 @@ def _calibrate_dtype(dtype, mesh, ndev, n):
     what a bf16 compute plan's cost model should be fed)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from benchmarks.common import emit, time_fn
@@ -68,7 +67,7 @@ def _calibrate_dtype(dtype, mesh, ndev, n):
         x = jnp.ones((ndev * side, side), dtype)
 
         @jax.jit
-        @functools.partial(shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=P("sep", None), out_specs=P("sep", None))
         def allreduce(blk):
             # each device contributes its (side, side) block; one psum
@@ -149,17 +148,25 @@ def _calibrate():
 
 
 def run():
-    """Suite entry for ``benchmarks.run``: re-exec with NDEV virtual
-    devices when this process has too few, re-emitting the subprocess
-    rows (same protocol as ``grouped_scaling``)."""
+    """Suite entry for ``benchmarks.run``: on a CPU host with too few
+    devices, re-exec with NDEV virtual CPU devices, re-emitting the
+    subprocess rows; on an accelerator host with too few devices, raise
+    (same protocol as ``grouped_scaling``)."""
     import jax
     from benchmarks.common import emit
 
     if jax.device_count() >= NDEV:
         _calibrate()
         return
+    if jax.default_backend() != "cpu":
+        # an accelerator belongs to this process: a child could not
+        # reach it, and virtual CPU devices would time the wrong chip
+        raise RuntimeError(
+            f"comm_calibrate needs {NDEV} devices; this "
+            f"{jax.default_backend()} host has {jax.device_count()}")
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS=f"--xla_force_host_platform_device_count={NDEV}",
         JAX_ENABLE_X64="1")
     out = subprocess.run([sys.executable, "-m", "benchmarks.comm_calibrate"],

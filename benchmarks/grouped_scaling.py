@@ -112,17 +112,25 @@ def _sweep():
 
 
 def run():
-    """Suite entry for ``benchmarks.run``: re-exec with NDEV virtual
-    devices when this process has too few (the harness process imported
-    jax long ago), re-emitting the subprocess rows."""
+    """Suite entry for ``benchmarks.run``: on a CPU host with too few
+    devices, re-exec with NDEV virtual CPU devices (the harness process
+    imported jax long ago), re-emitting the subprocess rows; on an
+    accelerator host with too few devices, raise."""
     import jax
     from benchmarks.common import emit
 
     if jax.device_count() >= NDEV:
         _sweep()
         return
+    if jax.default_backend() != "cpu":
+        # an accelerator belongs to this process: a child could not
+        # reach it, and virtual CPU devices would time the wrong chip
+        raise RuntimeError(
+            f"grouped_scaling needs {NDEV} devices; this "
+            f"{jax.default_backend()} host has {jax.device_count()}")
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS=f"--xla_force_host_platform_device_count={NDEV}",
         JAX_ENABLE_X64="1")
     out = subprocess.run([sys.executable, "-m", "benchmarks.grouped_scaling"],

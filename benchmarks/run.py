@@ -54,12 +54,16 @@ def main() -> None:
     args = ap.parse_args()
     names = (args.only.split(",") if args.only else list(SUITES))
     print("name,us_per_call,derived")
+    broken = []
     for name in names:
         try:
             SUITES[name]()
         except Exception as e:  # keep the harness going; report the break
+            broken.append(name)
             print(f"{name}.ERROR,0.0,{type(e).__name__}:{str(e)[:120]}",
                   flush=True)
+    if broken:
+        sys.exit(f"broken suites: {','.join(broken)}")
 
 
 if __name__ == "__main__":

@@ -5,15 +5,19 @@ plan time, with the DGSUM2D combine as psum('zolo').
 Also runs the paper-faithful vs gram-shared flop accounting (the
 beyond-paper optimization of DESIGN.md §3).
 
-  python examples/distributed_svd.py      (sets its own XLA_FLAGS;
-                                           needs `pip install -e .` or
-                                           PYTHONPATH=src)
+  JAX_PLATFORMS=cpu python examples/distributed_svd.py
+      (on the CPU it sets its own XLA_FLAGS for 8 virtual devices; on a
+      TPU host run it without JAX_PLATFORMS to use the real chips;
+      needs `pip install -e .` or PYTHONPATH=src)
 """
 
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-os.environ.setdefault("JAX_ENABLE_X64", "1")
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    # 8 virtual host devices and f64 numerics: CPU runs only — on an
+    # accelerator the real devices and the f32 contract stand
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
@@ -38,7 +42,8 @@ def main():
     for r in (2, 4):
         mesh = zolo_group_mesh(r)
         print(f"\nr={r}: mesh = {dict(mesh.shape)}  "
-              f"(TOP context = {r} groups, SEP = {8 // r} devices each)")
+              f"(TOP context = {r} groups, SEP = {mesh.shape['sep']} "
+              f"devices each)")
         # the mesh makes mode resolve to "grouped"; the Zolotarev
         # schedule is precomputed at plan time and the compiled
         # executable is cached per (shape, dtype, config, mesh)

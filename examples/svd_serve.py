@@ -3,15 +3,19 @@ request stream — tall, wide, two dtypes, two accuracy modes — bucketed
 into a padded plan pool, continuously micro-batched, and dispatched with
 the batch axis sharded one-matrix-per-device across the mesh.
 
-  python examples/svd_serve.py        (sets its own XLA_FLAGS;
-                                       needs `pip install -e .` or
-                                       PYTHONPATH=src)
+  JAX_PLATFORMS=cpu python examples/svd_serve.py
+      (on the CPU it sets its own XLA_FLAGS for 8 virtual devices; on a
+      TPU host run it without JAX_PLATFORMS to use the real chips;
+      needs `pip install -e .` or PYTHONPATH=src)
 """
 
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-os.environ.setdefault("JAX_ENABLE_X64", "1")
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    # 8 virtual host devices and f64 numerics: CPU runs only — on an
+    # accelerator the real devices and the f32 contract stand
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 import numpy as np  # noqa: E402
 import jax  # noqa: E402

@@ -47,9 +47,9 @@ __all__ = [
     "iter_eqns",
 ]
 
-# every shard_map spelling of an all-reduce; the rep-checker rewrites
-# psum -> psum2 under check_rep=True, newer jax uses psum_invariant
-PSUM_PRIMS = {"psum", "psum2", "psum_invariant"}
+# every shard_map spelling of an all-reduce: jax.shard_map emits
+# psum_invariant under check_vma=True and plain psum under check_vma=False
+PSUM_PRIMS = {"psum", "psum_invariant"}
 COLLECTIVE_PRIMS = PSUM_PRIMS | {
     "pmax", "pmin", "ppermute", "all_gather", "all_to_all",
     "reduce_scatter", "psum_scatter", "axis_index",

@@ -130,7 +130,7 @@ class FileContext:
     def comment_near(self, line: int, *, lookback: int = 6) -> str:
         """Concatenated comment text on ``line`` and up to ``lookback``
         contiguous comment/blank lines above it — the justification
-        window rules search for tags like ``check_rep``."""
+        window rules search for tags like ``check_vma``."""
         parts = []
         if line in self.comments:
             parts.append(self.comments[line])

@@ -6,7 +6,7 @@ rule                historical bug it encodes
 collective-axis     PR 4: psum/axis_index against an axis name that is
                     not bound by the surrounding mesh traces fine on one
                     device and deadlocks/miscomputes on a real slice;
-                    ``check_rep=False`` without a written justification
+                    ``check_vma=False`` without a written justification
                     hides replication-rule bugs (the double-psum class).
 accum-dtype         PR 3: a Gram/einsum product without
                     ``preferred_element_type`` accumulates bf16/f16 on
@@ -92,12 +92,12 @@ def _functions(tree: ast.AST) -> List[ast.FunctionDef]:
 class CollectiveAxisRule:
     """psum/axis_index axis names must be declared somewhere in the module
     (mesh construction, PartitionSpec, or an ``axis=``-style parameter
-    default); ``check_rep=False`` needs a justification comment that
-    mentions ``check_rep``."""
+    default); ``check_vma=False`` needs a justification comment that
+    mentions ``check_vma``."""
 
     name = "collective-axis"
     doc = ("collective axis literals must match a declared mesh axis; "
-           "check_rep=False requires a 'check_rep' justification comment")
+           "check_vma=False requires a 'check_vma' justification comment")
 
     COLLECTIVES = {"psum", "pmean", "pmax", "pmin", "all_gather",
                    "axis_index", "psum_scatter", "ppermute", "pshuffle",
@@ -164,15 +164,15 @@ class CollectiveAxisRule:
                                 node, self.name,
                                 f"{tail}(..., {lit!r}): no mesh axes are "
                                 f"declared in this module at all")
-            kw = _kwarg(node, "check_rep")
+            kw = _kwarg(node, "check_vma")
             if (kw is not None and isinstance(kw, ast.Constant)
                     and kw.value is False):
                 near = ctx.comment_near(node.lineno)
-                if "check_rep" not in near:
+                if "check_vma" not in near:
                     yield ctx.finding(
                         node, self.name,
-                        "check_rep=False without a justification comment "
-                        "mentioning 'check_rep' (replication-rule checking "
+                        "check_vma=False without a justification comment "
+                        "mentioning 'check_vma' (replication-rule checking "
                         "caught the PR 4 double-psum class)")
 
 

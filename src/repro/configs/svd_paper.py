@@ -12,6 +12,8 @@ benchmarks; full-sized entries drive flop/roofline accounting.
 from __future__ import annotations
 
 import dataclasses
+import zlib
+from typing import Optional
 
 import numpy as np
 
@@ -43,16 +45,30 @@ QR_SHAPES = [(10_000, 5_000), (20_000, 10_000)]
 QR_CPU_SHAPES = [(1_536, 768), (3_072, 1_536)]
 
 
-def synthesize(name: str, *, cpu_size: bool = True, dtype=np.float64,
-               seed: int = 0) -> np.ndarray:
-    """Dense synthetic stand-in with matched n (or cpu_n) and kappa_2."""
+def spectrum(name: str, *, cpu_size: bool = True,
+             n: Optional[int] = None) -> np.ndarray:
+    """The exact singular values :func:`synthesize` builds in, descending
+    (geometric from 1 to 1/kappa); ``n`` overrides the size."""
     if name not in MATRICES:
         raise ValueError(f"unknown paper matrix {name!r}; known: "
                          f"{sorted(MATRICES)}")
     cfg = MATRICES[name]
-    n = cfg.cpu_n if cpu_size else cfg.n
-    rng = np.random.default_rng(seed + hash(name) % (2 ** 16))
-    s = np.geomspace(1.0, 1.0 / cfg.cond, n)
+    if n is None:
+        n = cfg.cpu_n if cpu_size else cfg.n
+    return np.geomspace(1.0, 1.0 / cfg.cond, n)
+
+
+def synthesize(name: str, *, cpu_size: bool = True, n: Optional[int] = None,
+               dtype=np.float64, seed: int = 0) -> np.ndarray:
+    """Dense synthetic stand-in with matched n (or cpu_n, or the given
+    ``n``) and kappa_2.
+
+    The matrix is a function of (name, size, seed) alone: the per-matrix
+    seed offset is a CRC of the name, not ``hash()``, which Python
+    randomizes per process."""
+    s = spectrum(name, cpu_size=cpu_size, n=n)
+    n = s.shape[0]
+    rng = np.random.default_rng(seed + zlib.crc32(name.encode()) % (2 ** 16))
     # Haar-random U, V via QR of Gaussian
     u, _ = np.linalg.qr(rng.standard_normal((n, n)))
     v, _ = np.linalg.qr(rng.standard_normal((n, n)))

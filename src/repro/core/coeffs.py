@@ -33,13 +33,9 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from scipy import special as _scipy_special
 
 from repro.core import elliptic
-
-try:  # scipy is available in this environment; keep a guard for portability
-    from scipy import special as _scipy_special
-except ImportError:  # pragma: no cover
-    _scipy_special = None
 
 # Machine-epsilon targets used for convergence tests (paper: 1e-15 band).
 EPS64 = 1.1e-16
@@ -112,7 +108,7 @@ def zolo_fn_product(x, c, mhat):
 
 
 def _ellipj_mc_np(u, mc):
-    if _scipy_special is not None and mc > 1e-14:
+    if mc > 1e-14:
         sn, cn, dn, _ = _scipy_special.ellipj(np.asarray(u), 1.0 - mc)
         return sn, cn, dn
     try:
@@ -135,9 +131,7 @@ def _ellipj_mc_np(u, mc):
 
 
 def _ellipk_mc_np(mc):
-    if _scipy_special is not None:
-        return float(_scipy_special.ellipkm1(mc))
-    return float(elliptic.ellipk_mc(jnp.float64(mc)))
+    return float(_scipy_special.ellipkm1(mc))
 
 
 def zolo_coeffs_np(l: float, r: int):

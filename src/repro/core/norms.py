@@ -107,7 +107,8 @@ def sigma_min_lower(x, iters: int = 8, safety: float = 0.5, *, gram=None):
             l, y, left_side=True, lower=True, transpose_a=True)
         return z[..., 0]
 
-    v = jnp.ones(x.shape[:-2] + (n,), dtype=dtype) / jnp.sqrt(n).astype(dtype)
+    v = jnp.ones(x.shape[:-2] + (n,), dtype=dtype) / jnp.sqrt(
+        jnp.asarray(n, dtype))
 
     def body(_, v):
         w = solve(v)
@@ -144,7 +145,8 @@ def sigma_min_lower_qr(x, iters: int = 12, safety: float = 0.5):
             r, y, left_side=True, lower=False)
         return z[..., 0]
 
-    v = jnp.ones(x.shape[:-2] + (n,), dtype=dtype) / jnp.sqrt(n).astype(dtype)
+    v = jnp.ones(x.shape[:-2] + (n,), dtype=dtype) / jnp.sqrt(
+        jnp.asarray(n, dtype))
 
     def body(_, v):
         w = solve(v)

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from repro.core import coeffs as _coeffs
 from repro.core import norms as _norms
+from repro.core.trisolve import solve_lower
 
 
 class PolarInfo(NamedTuple):
@@ -56,8 +57,12 @@ def _eps_for(dtype) -> float:
 
 
 def form_h(q, a):
-    """H = (Q^T A + (Q^T A)^T) / 2 — the Hermitian polar factor."""
-    qa = jnp.einsum("...mk,...mn->...kn", q, a)
+    """H = (Q^T A + (Q^T A)^T) / 2 — the Hermitian polar factor.
+
+    HIGHEST: H's eigenvalues are the singular values, and at TPU DEFAULT
+    precision an f32 product runs as one bf16 pass (~2e-3 relative)."""
+    qa = jnp.einsum("...mk,...mn->...kn", q, a,
+                    precision=jax.lax.Precision.HIGHEST)
     return 0.5 * (qa + jnp.swapaxes(qa, -1, -2))
 
 
@@ -73,8 +78,10 @@ def _qdwh_qr_iter(x, a, b, c):
     q1 = q[..., :m, :]
     q2 = q[..., m:, :]
     coef = ((a - b / c) / jnp.sqrt(c)).astype(dtype)
+    # HIGHEST here and in the Gram below: the products are the new
+    # iterate, and TPU DEFAULT precision runs f32 as one bf16 pass
     return (b / c).astype(dtype) * x + coef * jnp.einsum(
-        "...mk,...nk->...mn", q1, q2)
+        "...mk,...nk->...mn", q1, q2, precision=jax.lax.Precision.HIGHEST)
 
 
 def _qdwh_chol_iter(x, a, b, c):
@@ -83,14 +90,14 @@ def _qdwh_chol_iter(x, a, b, c):
     dtype = x.dtype
     g = jnp.einsum("...mk,...mn->...kn", x, x,
                    preferred_element_type=jnp.promote_types(
-                       dtype, jnp.float32)).astype(dtype)
+                       dtype, jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST).astype(dtype)
     z = c.astype(dtype) * g + jnp.eye(n, dtype=dtype)
     l = jnp.linalg.cholesky(z)
     # W = Z^{-1} X^T via two triangular solves.
     xt = jnp.swapaxes(x, -1, -2)
-    y = jax.lax.linalg.triangular_solve(l, xt, left_side=True, lower=True)
-    w = jax.lax.linalg.triangular_solve(
-        l, y, left_side=True, lower=True, transpose_a=True)
+    y = solve_lower(l, xt, left_side=True)
+    w = solve_lower(l, y, left_side=True, transpose_a=True)
     xz = jnp.swapaxes(w, -1, -2)
     return (b / c).astype(dtype) * x + (a - b / c).astype(dtype) * xz
 

@@ -62,6 +62,7 @@ from repro.core import coeffs as _coeffs
 from repro.core import norms as _norms
 from repro.core.qdwh import PolarInfo, form_h
 from repro.core.structured_qr import structured_qr_q1q2 as _structured_qr_q1q2
+from repro.core.trisolve import solve_lower
 
 
 # Ridge floor multiplier for the shifted-Gram coefficient in sub-f64
@@ -91,10 +92,14 @@ def _clamp_shift(c_odd, g, dtype):
 
 
 def _gram(x, c=0.0):
-    """G = X^T X (+ c I) with f32-or-better accumulation."""
+    """G = X^T X (+ c I) with f32-or-better accumulation.
+
+    HIGHEST: the Cholesky factors this Gram, and at TPU DEFAULT
+    precision an f32 product runs as one bf16 pass (~2e-3 relative)."""
     g = jnp.einsum("...mk,...mn->...kn", x, x,
                    preferred_element_type=jnp.promote_types(x.dtype,
-                                                            jnp.float32))
+                                                            jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
     if isinstance(c, (int, float)) and c == 0.0:
         return g
     n = x.shape[-1]
@@ -203,9 +208,8 @@ def _chol_terms(x, c_odd, gram=None, *, ops: ZoloOps = DEFAULT_OPS):
     xt = jnp.broadcast_to(
         jnp.swapaxes(x, -1, -2).astype(fdtype),
         (r,) + x.shape[:-2] + (n, x.shape[-2]))
-    y = jax.lax.linalg.triangular_solve(l, xt, left_side=True, lower=True)
-    w = jax.lax.linalg.triangular_solve(
-        l, y, left_side=True, lower=True, transpose_a=True)
+    y = solve_lower(l, xt, left_side=True)
+    w = solve_lower(l, y, left_side=True, transpose_a=True)
     return w  # (r, n, m), fdtype
 
 
@@ -254,23 +258,22 @@ def term_sum_cholqr2(x, c_odd, a, *, ops: ZoloOps = DEFAULT_OPS):
     l1 = jnp.linalg.cholesky(z)  # R1 = L1^T
     xb = jnp.broadcast_to(x.astype(fdtype), (r,) + x.shape)
     # Q1 = X R1^{-1}  (right-solve against upper-triangular R1 = L1^T)
-    q1 = jax.lax.linalg.triangular_solve(
-        l1, xb, left_side=False, lower=True, transpose_a=True)
+    q1 = solve_lower(l1, xb, left_side=False, transpose_a=True)
     # Q2 = sqrt(c) R1^{-1}
-    q2 = sqrt_c[:, None, None] * jax.lax.linalg.triangular_solve(
-        l1, jnp.broadcast_to(eye, (r, n, n)),
-        left_side=False, lower=True, transpose_a=True)
+    q2 = sqrt_c[:, None, None] * solve_lower(
+        l1, jnp.broadcast_to(eye, (r, n, n)), left_side=False,
+        transpose_a=True)
     # Second pass restores orthogonality: G2 = Q^T Q = Q1^T Q1 + Q2^T Q2.
     # The Grams take the *iterate* dtype so a sub-f32 bundle's kernels
     # run the production precision (no-op cast for f32/f64).
     g2 = (ops.gram(q1.astype(x.dtype))
           + ops.gram_local(q2.astype(x.dtype))).astype(fdtype)
     l2 = jnp.linalg.cholesky(g2)
-    q1 = jax.lax.linalg.triangular_solve(
-        l2, q1, left_side=False, lower=True, transpose_a=True)
-    q2 = jax.lax.linalg.triangular_solve(
-        l2, q2, left_side=False, lower=True, transpose_a=True)
-    return jnp.einsum("j,jmk,jnk->mn", a.astype(fdtype) / sqrt_c, q1, q2)
+    q1 = solve_lower(l2, q1, left_side=False, transpose_a=True)
+    q2 = solve_lower(l2, q2, left_side=False, transpose_a=True)
+    # the Q1 Q2^T product is the new iterate's term: HIGHEST, as _gram
+    return jnp.einsum("j,jmk,jnk->mn", a.astype(fdtype) / sqrt_c, q1, q2,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def term_sum_householder(x, c_odd, a, block: int = 32, *,

@@ -45,7 +45,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import coeffs as _coeffs
@@ -205,17 +204,17 @@ def grouped_zolo_pd_static(a, *, mesh: Mesh, l0: Optional[float] = None,
         combine_kernel = _default_combine_kernel(a.dtype)
     if gram_kernel is None:
         gram_kernel = _default_gram_kernel(a.dtype)
-    # pallas_call has no shard_map replication rule, so check_rep must be
+    # pallas_call has no shard_map replication rule, so check_vma must be
     # False whenever ANY Pallas kernel (combine or gram) runs in the
     # body; the psum over "zolo" establishes the out_specs replication
     # either way, so rep checking is only disabled when a kernel path
     # actually runs
-    check_rep = not (combine_kernel or gram_kernel)
+    check_vma = not (combine_kernel or gram_kernel)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(x_spec, P(None, "zolo"), P(None, "zolo"), P()),
-        out_specs=x_spec, check_rep=check_rep)
+        out_specs=x_spec, check_vma=check_vma)
     def run(x, c_grp, a_grp, mh):
         # c_grp / a_grp: (iters, 1) — this group's shift and weight per
         # iteration.  x: this device's (m_pad/sep, n) row block of the
@@ -304,15 +303,15 @@ def grouped_zolo_pd_dynamic(a, *, mesh: Mesh, r: Optional[int] = None,
     if gram_kernel is None:
         gram_kernel = _default_gram_kernel(dtype)
 
-    # check_rep=False: the rep checker cannot type the fori_loop carry of
+    # check_vma=False: the rep checker cannot type the fori_loop carry of
     # the in-graph sigma_min estimate (the loop runs on the post-psum —
     # replicated — Gram, but the checker rejects the carry's widening
     # replication; jax suggests exactly this workaround).  Replication is
     # established by construction: every scalar derives from "sep"-psum
     # results and the iterate from the "zolo" combine psum.
-    @functools.partial(shard_map, mesh=mesh, in_specs=(x_spec,),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(x_spec,),
                        out_specs=(x_spec, P(), P(), P(), P(), P()),
-                       check_rep=False)
+                       check_vma=False)
     def run(x):
         if x.shape != (m_pad // nsep, n):
             raise AssertionError(

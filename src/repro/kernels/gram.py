@@ -18,7 +18,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+
+# Constant block index: a Python 0 becomes i64 under jax_enable_x64,
+# and Mosaic refuses an i64 index map.
+_ZERO = np.int32(0)
 
 # Accumulation dtype of every dot in this kernel; sub-f32 inputs (bf16)
 # are legal because the MXU widens to this before summing.  The
@@ -49,9 +54,13 @@ def _gram_kernel(a1_ref, a2_ref, c_ref, out_ref, *, n_k: int, bn: int):
 
     a1 = a1_ref[...]
     a2 = a2_ref[...]
+    # f32 inputs take the full-f32 MXU contraction (Mosaic's default is
+    # a bf16 pass); bf16 inputs are exact in one pass
     out_ref[...] += jax.lax.dot_general(
         a1, a2, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.HIGHEST if a1.dtype == jnp.float32
+                   else None))
 
     @pl.when(jnp.logical_and(k == n_k - 1, i == j))
     def _shift_diag():
@@ -93,7 +102,7 @@ def gram_kernel_call(a, c, *, bn: int = 256, bk: int = 512,
         in_specs=[
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, i)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1,), lambda i, j, k: (0,)),
+            pl.BlockSpec((1,), lambda i, j, k: (_ZERO,)),
         ],
         out_specs=pl.BlockSpec((bn, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),

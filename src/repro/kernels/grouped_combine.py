@@ -25,7 +25,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+
+# Constant block index: a Python 0 becomes i64 under jax_enable_x64,
+# and Mosaic refuses an i64 index map.
+_ZERO = np.int32(0)
 
 # The combine accumulates in f32 whatever the iterate dtype; the paired
 # conditioning envelope is ``repro.core.svd.PALLAS_KAPPA_ENVELOPE``.
@@ -70,9 +75,9 @@ def grouped_combine_kernel_call(x, t, a, mhat, xw, *, bm: int = 256,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((r, bm, bn), lambda i, j: (0, i, j)),
-            pl.BlockSpec((r,), lambda i, j: (0,)),
-            pl.BlockSpec((2,), lambda i, j: (0,)),
+            pl.BlockSpec((r, bm, bn), lambda i, j: (_ZERO, i, j)),
+            pl.BlockSpec((r,), lambda i, j: (_ZERO,)),
+            pl.BlockSpec((2,), lambda i, j: (_ZERO,)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),

@@ -11,7 +11,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+
+# Constant block index: a Python 0 becomes i64 under jax_enable_x64,
+# and Mosaic refuses an i64 index map.
+_ZERO = np.int32(0)
 
 # f32 accumulation for any input dtype (bf16 included); the paired
 # conditioning envelope is ``repro.core.svd.PALLAS_KAPPA_ENVELOPE``.
@@ -58,7 +63,7 @@ def matmul_kernel_call(a, b, alpha=1.0, *, bm: int = 256, bn: int = 256,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1,), lambda i, j, k: (0,)),
+            pl.BlockSpec((1,), lambda i, j, k: (_ZERO,)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),

@@ -30,6 +30,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import ServiceConfig, SvdService
 
 
@@ -144,6 +145,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     service = SvdService(ServiceConfig(batch_size=args.batch,
                                        max_wait=args.max_wait))
     rec = run_workload(service, _parse_shapes(args.shapes),

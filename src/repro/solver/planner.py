@@ -580,8 +580,18 @@ class SvdPlan:
         # plan hands H to the eig stage at the accumulation precision
         # (no-op for f32/f64 — promote_types is the identity there)
         h = h.astype(jnp.promote_types(h.dtype, jnp.float32))
+        if self.mode == "grouped":
+            # the eig stage is not distributed: every device solves the
+            # whole H, rather than the partitioner splitting the
+            # eigensolver's loops over the mesh
+            h = jax.lax.with_sharding_constraint(
+                h, jax.sharding.NamedSharding(self.mesh,
+                                              jax.sharding.PartitionSpec()))
         w, v = self._eig_spec.fn(h, **self._eig_kwargs)
-        u = jnp.einsum("...mk,...kn->...mn", q, v)
+        # HIGHEST: U is a result, and at TPU DEFAULT precision an f32
+        # product runs as one bf16 pass (~2e-3 relative)
+        u = jnp.einsum("...mk,...kn->...mn", q, v,
+                       precision=jax.lax.Precision.HIGHEST)
         # ascending -> descending; fold any tiny negative eigenvalue's
         # sign into U so that s >= 0.
         sign = jnp.where(w < 0, -1.0, 1.0).astype(u.dtype)
@@ -660,6 +670,13 @@ class SvdPlan:
         """A = U diag(s) V^H (paper Alg. 2), s descending — compiled."""
         self._check(a)
         return self._executable(("svd",), self._svd_impl)(a)
+
+    def compile_svd(self, a):
+        """Trace, lower and compile ``svd`` for ``a`` ahead of its first
+        call; returns the ``jax.stages.Compiled``, whose ``as_text()`` is
+        the HLO the device runs.  A later ``svd(a)`` reuses it."""
+        self._check(a)
+        return self._executable(("svd",), self._svd_impl).lower(a).compile()
 
     def polar(self, a, want_h: bool = True):
         """(q, h, info) with A ~= Q H — compiled."""

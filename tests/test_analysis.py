@@ -70,20 +70,20 @@ def test_collective_axis_accepts_declared_axes(tmp_path):
 
 def test_collective_axis_check_rep_needs_justification(tmp_path):
     bad = lint(tmp_path, """
-        from jax.experimental.shard_map import shard_map
+        import jax
         def run(f, mesh, specs):
-            return shard_map(f, mesh, in_specs=specs, out_specs=specs,
-                             check_rep=False)
+            return jax.shard_map(f, mesh=mesh, in_specs=specs,
+                                 out_specs=specs, check_vma=False)
         """, "collective-axis")
     assert len(bad.findings) == 1
-    assert "check_rep" in bad.findings[0].message
+    assert "check_vma" in bad.findings[0].message
     good = lint(tmp_path, """
-        from jax.experimental.shard_map import shard_map
+        import jax
         def run(f, mesh, specs):
-            # check_rep=False: the rep checker rejects the one-hot xw
+            # check_vma=False: the rep checker rejects the one-hot xw
             # combine; the psum budget is enforced by the jaxpr audit
-            return shard_map(f, mesh, in_specs=specs, out_specs=specs,
-                             check_rep=False)
+            return jax.shard_map(f, mesh=mesh, in_specs=specs,
+                                 out_specs=specs, check_vma=False)
         """, "collective-axis")
     assert good.findings == []
 

@@ -190,6 +190,13 @@ def test_registry_roundtrip_and_dispatch():
             C.polar_decompose(a, method="_test_dummy", mesh=object())
     finally:
         registry.unregister_polar("_test_dummy")
+        # drop its cached plans too, so the session-end audit of every
+        # cached plan (test_analysis) never meets a backend that is gone
+        from repro.solver import planner as planner_mod
+
+        for key in [k for k, v in planner_mod._PLANS.items()
+                    if v.method == "_test_dummy"]:
+            del planner_mod._PLANS[key]
     assert "_test_dummy" not in registry.list_polar()
 
 

@@ -58,6 +58,21 @@ def test_block_jacobi_eigh_padded_sizes():
     np.testing.assert_allclose(np.asarray(w), w0, atol=1e-11)
 
 
+@pytest.mark.parametrize("n", [1000, 1024])
+def test_block_jacobi_eigh_f32_clustered_spectrum(n):
+    """nemeth03's H in f32 (eigenvalues in [1/1.29, 1]), padded (1000)
+    and not (1024), at the chip's block size: eigenvalues to the accuracy
+    of LAPACK's f32 eigh, which the diagonal the sweeps leave, or an
+    unshifted H, or a corner of large padding misses by 10-100x."""
+    rng = np.random.default_rng(n)
+    q, _ = np.linalg.qr(rng.standard_normal((n, n)))
+    w0 = np.geomspace(1.0 / 1.29, 1.0, n)
+    h = ((q * w0) @ q.T).astype(np.float32)
+    w, v = C.padded_block_jacobi_eigh(jnp.asarray(h), nb=128)
+    assert float(np.max(np.abs(np.asarray(w, np.float64) - w0))) < 5e-7
+    assert float(C.orthogonality(v.astype(jnp.float64))) < 1e-7
+
+
 def test_polar_svd_with_jacobi_eig():
     a = make_matrix(64, 64, 100.0, seed=12)
     u, s, vh = C.polar_svd(a, method="zolo", eig_method="jacobi", nb=16)
