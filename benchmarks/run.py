@@ -23,7 +23,6 @@ from benchmarks import (  # noqa: E402
     iterations,
     kernels_bench,
     pd_compare,
-    pd_profile,
     roofline,
     structured_qr_bench,
     svd_compare,
@@ -36,7 +35,6 @@ SUITES = {
     "structured_qr": structured_qr_bench.run,  # paper Table 2
     "svd_compare": svd_compare.run,     # paper Tables 4, 9
     "pd_compare": pd_compare.run,       # paper Table 6
-    "pd_profile": pd_profile.run,       # paper Table 7
     "accuracy": accuracy.run,           # paper Figure 2
     "kernels": kernels_bench.run,       # Pallas kernel parity
     "grouped_scaling": grouped_scaling.run,  # Alg. 3 (r, sep) sweep

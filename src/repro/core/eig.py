@@ -87,64 +87,70 @@ def block_jacobi_eigh(h, nb: int = 32, max_sweeps: int = 12, tol=None,
 
     def do_round(carry, pairs):
         h, v = carry
-        p = pairs[:, 0]
-        q = pairs[:, 1]
-        # gather row indices for each pair: (npairs, 2*nb)
-        row_ids = (jnp.concatenate(
-            [p[:, None] * nb + jnp.arange(nb)[None, :],
-             q[:, None] * nb + jnp.arange(nb)[None, :]], axis=1))
-        rows = h[row_ids.reshape(-1), :].reshape(-1, 2 * nb, n)
-        # subproblem S_i = rows_i[:, row_ids_i]
-        sub = jnp.take_along_axis(
-            rows, row_ids[:, None, :].repeat(2 * nb, axis=1), axis=2)
-        sub = 0.5 * (sub + jnp.swapaxes(sub, -1, -2))
-        if n_real < n:
-            real = row_ids < n_real
-            c = 1.01 * jnp.max(jnp.sum(jnp.abs(sub), axis=-1), axis=-1)
-            c = c + jnp.finfo(dtype).tiny
-            sub = sub + (c[:, None] * (~real).astype(dtype))[
-                :, :, None] * jnp.eye(2 * nb, dtype=dtype)
-        _, j = jnp.linalg.eigh(sub)  # (npairs, 2nb, 2nb)
-        if n_real < n:
-            j = j * (real[:, :, None] == real[:, None, :]).astype(j.dtype)
-        # HIGHEST on the rotations: they are the eigenvectors and the
-        # next round's H, and at TPU DEFAULT precision an f32 product
-        # runs as one bf16 pass (~2e-3 relative)
-        hi = jax.lax.Precision.HIGHEST
-        # one Newton-Schulz step, J (3I - J^T J) / 2: XLA's TPU Jacobi
-        # returns J with |J^T J - I| up to ~7e-6, and every round that
-        # rotates H by a J that far from orthogonal moves its spectrum
-        acc = jnp.promote_types(dtype, jnp.float32)
-        jtj = jnp.einsum("pki,pkj->pij", j, j, precision=hi,
-                         preferred_element_type=acc)
-        j = jnp.einsum("pik,pkj->pij", j,
-                       1.5 * jnp.eye(2 * nb, dtype=acc) - 0.5 * jtj,
-                       precision=hi, preferred_element_type=acc
-                       ).astype(dtype)
-        # row phase: rows <- J^T rows
-        rows_new = jnp.einsum("pij,pin->pjn", j, rows, precision=hi,
-                              preferred_element_type=acc).astype(dtype)
-        h = h.at[row_ids.reshape(-1), :].set(rows_new.reshape(-1, n))
-        # column phase: cols <- cols J
-        cols = h[:, row_ids.reshape(-1)].reshape(n, -1, 2 * nb)
-        cols = jnp.swapaxes(cols, 0, 1)  # (npairs, n, 2nb)
-        cols_new = jnp.einsum("pni,pij->pnj", cols, j, precision=hi,
-                              preferred_element_type=acc).astype(dtype)
-        h = h.at[:, row_ids.reshape(-1)].set(
-            jnp.swapaxes(cols_new, 0, 1).reshape(n, -1))
-        # accumulate eigenvectors: V <- V J (column op)
-        vcols = v[:, row_ids.reshape(-1)].reshape(n, -1, 2 * nb)
-        vcols = jnp.swapaxes(vcols, 0, 1)
-        vcols_new = jnp.einsum("pni,pij->pnj", vcols, j, precision=hi)
-        v = v.at[:, row_ids.reshape(-1)].set(
-            jnp.swapaxes(vcols_new, 0, 1).reshape(n, -1))
+        with jax.named_scope("rotate"):
+            p = pairs[:, 0]
+            q = pairs[:, 1]
+            # gather row indices for each pair: (npairs, 2*nb)
+            row_ids = (jnp.concatenate(
+                [p[:, None] * nb + jnp.arange(nb)[None, :],
+                 q[:, None] * nb + jnp.arange(nb)[None, :]], axis=1))
+            rows = h[row_ids.reshape(-1), :].reshape(-1, 2 * nb, n)
+            # subproblem S_i = rows_i[:, row_ids_i]
+            sub = jnp.take_along_axis(
+                rows, row_ids[:, None, :].repeat(2 * nb, axis=1), axis=2)
+            sub = 0.5 * (sub + jnp.swapaxes(sub, -1, -2))
+            if n_real < n:
+                real = row_ids < n_real
+                c = 1.01 * jnp.max(jnp.sum(jnp.abs(sub), axis=-1), axis=-1)
+                c = c + jnp.finfo(dtype).tiny
+                sub = sub + (c[:, None] * (~real).astype(dtype))[
+                    :, :, None] * jnp.eye(2 * nb, dtype=dtype)
+        with jax.named_scope("small_eigh"):
+            _, j = jnp.linalg.eigh(sub)  # (npairs, 2nb, 2nb)
+        with jax.named_scope("rotate"):
+            if n_real < n:
+                j = j * (real[:, :, None] == real[:, None, :]).astype(
+                    j.dtype)
+            # HIGHEST on the rotations: they are the eigenvectors and the
+            # next round's H, and at TPU DEFAULT precision an f32 product
+            # runs as one bf16 pass (~2e-3 relative)
+            hi = jax.lax.Precision.HIGHEST
+            # one Newton-Schulz step, J (3I - J^T J) / 2: XLA's TPU Jacobi
+            # returns J with |J^T J - I| up to ~7e-6, and every round that
+            # rotates H by a J that far from orthogonal moves its spectrum
+            acc = jnp.promote_types(dtype, jnp.float32)
+            jtj = jnp.einsum("pki,pkj->pij", j, j, precision=hi,
+                             preferred_element_type=acc)
+            j = jnp.einsum("pik,pkj->pij", j,
+                           1.5 * jnp.eye(2 * nb, dtype=acc) - 0.5 * jtj,
+                           precision=hi, preferred_element_type=acc
+                           ).astype(dtype)
+            # row phase: rows <- J^T rows
+            rows_new = jnp.einsum("pij,pin->pjn", j, rows, precision=hi,
+                                  preferred_element_type=acc).astype(dtype)
+            h = h.at[row_ids.reshape(-1), :].set(rows_new.reshape(-1, n))
+            # column phase: cols <- cols J
+            cols = h[:, row_ids.reshape(-1)].reshape(n, -1, 2 * nb)
+            cols = jnp.swapaxes(cols, 0, 1)  # (npairs, n, 2nb)
+            cols_new = jnp.einsum("pni,pij->pnj", cols, j, precision=hi,
+                                  preferred_element_type=acc).astype(dtype)
+            h = h.at[:, row_ids.reshape(-1)].set(
+                jnp.swapaxes(cols_new, 0, 1).reshape(n, -1))
+            # accumulate eigenvectors: V <- V J (column op)
+            vcols = v[:, row_ids.reshape(-1)].reshape(n, -1, 2 * nb)
+            vcols = jnp.swapaxes(vcols, 0, 1)
+            vcols_new = jnp.einsum("pni,pij->pnj", vcols, j, precision=hi)
+            v = v.at[:, row_ids.reshape(-1)].set(
+                jnp.swapaxes(vcols_new, 0, 1).reshape(n, -1))
         return (h, v), None
 
     def sweep_body(state):
         h, v, s, off, _ = state
         (h, v), _ = jax.lax.scan(do_round, (h, v), sched)
-        new = _offdiag_norm(h, nb) / jnp.maximum(
-            jnp.sqrt(jnp.sum(h * h)), jnp.finfo(dtype).tiny)
+        # runs once per sweep: its device events count the sweeps
+        with jax.named_scope("sweep_test"):
+            new = _offdiag_norm(h, nb) / jnp.maximum(
+                jnp.sqrt(jnp.sum(h * h)), jnp.finfo(dtype).tiny)
         return h, v, s + 1, new, off
 
     def sweep_cond(state):
@@ -183,19 +189,21 @@ def padded_block_jacobi_eigh(h, nb: int = 32, max_sweeps: int = 12):
     (LAPACK's f32 eigh: 4.6e-7), and ||I - V^T V||_F / n is 1.5e-8
     against 2.7e-7 before the step."""
     n = h.shape[-1]
-    mu = jnp.trace(h) / n
-    hs = h - mu * jnp.eye(n, dtype=h.dtype)
+    with jax.named_scope("refine"):
+        mu = jnp.trace(h) / n
+        hs = h - mu * jnp.eye(n, dtype=h.dtype)
     _, v = _padded_block_jacobi_eigh(hs, nb=nb, max_sweeps=max_sweeps)
-    hi = jax.lax.Precision.HIGHEST
-    # one Newton-Schulz step, V (3I - V^T V) / 2, squares the loss of
-    # orthogonality the rounds accumulated in V
-    vtv = jnp.matmul(jnp.swapaxes(v, -1, -2), v, precision=hi)
-    v = jnp.matmul(v, 1.5 * jnp.eye(n, dtype=v.dtype) - 0.5 * vtv,
-                   precision=hi)
-    hv = jnp.matmul(hs, v, precision=hi)
-    w = jnp.sum(v * hv, axis=0) / jnp.sum(v * v, axis=0)
-    order = jnp.argsort(w)
-    return w[order] + mu, v[:, order]
+    with jax.named_scope("refine"):
+        hi = jax.lax.Precision.HIGHEST
+        # one Newton-Schulz step, V (3I - V^T V) / 2, squares the loss of
+        # orthogonality the rounds accumulated in V
+        vtv = jnp.matmul(jnp.swapaxes(v, -1, -2), v, precision=hi)
+        v = jnp.matmul(v, 1.5 * jnp.eye(n, dtype=v.dtype) - 0.5 * vtv,
+                       precision=hi)
+        hv = jnp.matmul(hs, v, precision=hi)
+        w = jnp.sum(v * hv, axis=0) / jnp.sum(v * v, axis=0)
+        order = jnp.argsort(w)
+        return w[order] + mu, v[:, order]
 
 
 def _padded_block_jacobi_eigh(h, nb: int, max_sweeps: int):

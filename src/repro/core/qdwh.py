@@ -56,6 +56,7 @@ def _eps_for(dtype) -> float:
     return float(jnp.finfo(dtype).eps)
 
 
+@jax.named_scope("form_h")
 def form_h(q, a):
     """H = (Q^T A + (Q^T A)^T) / 2 — the Hermitian polar factor.
 

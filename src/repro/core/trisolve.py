@@ -50,16 +50,21 @@ def _solve_upper(u, b):
     return x[..., :n, :]
 
 
+@jax.named_scope("trsm")
 def solve_lower(l, b, *, left_side: bool, transpose_a: bool = False):
     """``lax.linalg.triangular_solve(l, b, lower=True, left_side=...,
     transpose_a=...)`` for lower-triangular ``l`` (..., n, n), through
     the rolled blocked back substitution.  A forward solve runs on the
     reversed unknowns: with J the exchange matrix, J L J is upper
-    triangular and L y = b  <=>  (J L J)(J y) = J b."""
+    triangular and L y = b  <=>  (J L J)(J y) = J b.  Its operations
+    carry the name scope ``trsm``."""
     if not left_side:  # x op(L) = b  <=>  op(L)^T x^T = b^T
-        return jnp.swapaxes(solve_lower(
-            l, jnp.swapaxes(b, -1, -2), left_side=True,
-            transpose_a=not transpose_a), -1, -2)
+        return jnp.swapaxes(_solve_left(
+            l, jnp.swapaxes(b, -1, -2), not transpose_a), -1, -2)
+    return _solve_left(l, b, transpose_a)
+
+
+def _solve_left(l, b, transpose_a: bool):
     if transpose_a:  # L^T is upper triangular
         return _solve_upper(jnp.swapaxes(l, -1, -2), b)
     return jnp.flip(_solve_upper(jnp.flip(l, (-2, -1)), jnp.flip(b, -2)),

@@ -198,13 +198,19 @@ def _chol_terms(x, c_odd, gram=None, *, ops: ZoloOps = DEFAULT_OPS):
         # shift into the Gram call itself so a collective bundle carries
         # it inside the "sep" psum (fused shifted Gram) and the
         # kernel-/gram-side shift clamp applies
-        z = ops.gram(x, c_odd.astype(fdtype)[0])[None].astype(fdtype)
+        with jax.named_scope("gram"):
+            z = ops.gram(x, c_odd.astype(fdtype)[0])[None].astype(fdtype)
     else:
-        g = (ops.gram(x) if gram is None else gram).astype(fdtype)
+        if gram is None:
+            with jax.named_scope("gram"):
+                gram = ops.gram(x)
+        g = gram.astype(fdtype)
         eye = jnp.eye(n, dtype=fdtype)
-        c_eff = _clamp_shift(c_odd.astype(fdtype), g, x.dtype)
-        z = g[None] + c_eff[:, None, None] * eye  # (r, n, n)
-    l = jnp.linalg.cholesky(z)
+        with jax.named_scope("cholesky"):
+            c_eff = _clamp_shift(c_odd.astype(fdtype), g, x.dtype)
+            z = g[None] + c_eff[:, None, None] * eye  # (r, n, n)
+    with jax.named_scope("cholesky"):
+        l = jnp.linalg.cholesky(z)
     xt = jnp.broadcast_to(
         jnp.swapaxes(x, -1, -2).astype(fdtype),
         (r,) + x.shape[:-2] + (n, x.shape[-2]))
@@ -250,12 +256,16 @@ def term_sum_cholqr2(x, c_odd, a, *, ops: ZoloOps = DEFAULT_OPS):
     if r == 1:
         # fused shifted Gram: the shift rides the collective (see
         # _chol_terms); the gram implementation applies the shift clamp
-        z = ops.gram(x, c_odd_f[0])[None].astype(fdtype)
+        with jax.named_scope("gram"):
+            z = ops.gram(x, c_odd_f[0])[None].astype(fdtype)
     else:
-        g = ops.gram(x).astype(fdtype)
-        c_eff = _clamp_shift(c_odd_f, g, x.dtype)
-        z = g[None] + c_eff[:, None, None] * eye
-    l1 = jnp.linalg.cholesky(z)  # R1 = L1^T
+        with jax.named_scope("gram"):
+            g = ops.gram(x).astype(fdtype)
+        with jax.named_scope("cholesky"):
+            c_eff = _clamp_shift(c_odd_f, g, x.dtype)
+            z = g[None] + c_eff[:, None, None] * eye
+    with jax.named_scope("cholesky"):
+        l1 = jnp.linalg.cholesky(z)  # R1 = L1^T
     xb = jnp.broadcast_to(x.astype(fdtype), (r,) + x.shape)
     # Q1 = X R1^{-1}  (right-solve against upper-triangular R1 = L1^T)
     q1 = solve_lower(l1, xb, left_side=False, transpose_a=True)
@@ -266,14 +276,17 @@ def term_sum_cholqr2(x, c_odd, a, *, ops: ZoloOps = DEFAULT_OPS):
     # Second pass restores orthogonality: G2 = Q^T Q = Q1^T Q1 + Q2^T Q2.
     # The Grams take the *iterate* dtype so a sub-f32 bundle's kernels
     # run the production precision (no-op cast for f32/f64).
-    g2 = (ops.gram(q1.astype(x.dtype))
-          + ops.gram_local(q2.astype(x.dtype))).astype(fdtype)
-    l2 = jnp.linalg.cholesky(g2)
+    with jax.named_scope("gram"):
+        g2 = (ops.gram(q1.astype(x.dtype))
+              + ops.gram_local(q2.astype(x.dtype))).astype(fdtype)
+    with jax.named_scope("cholesky"):
+        l2 = jnp.linalg.cholesky(g2)
     q1 = solve_lower(l2, q1, left_side=False, transpose_a=True)
     q2 = solve_lower(l2, q2, left_side=False, transpose_a=True)
     # the Q1 Q2^T product is the new iterate's term: HIGHEST, as _gram
-    return jnp.einsum("j,jmk,jnk->mn", a.astype(fdtype) / sqrt_c, q1, q2,
-                      precision=jax.lax.Precision.HIGHEST)
+    with jax.named_scope("combine"):
+        return jnp.einsum("j,jmk,jnk->mn", a.astype(fdtype) / sqrt_c, q1,
+                          q2, precision=jax.lax.Precision.HIGHEST)
 
 
 def term_sum_householder(x, c_odd, a, block: int = 32, *,
@@ -331,8 +344,9 @@ def zolo_iteration(x, c_odd, a, mhat, *, mode: str = "chol",
     """
     if mode == "chol":
         w = _chol_terms(x, c_odd, ops=ops)    # (r, ..., n, m)
-        t = jnp.swapaxes(w, -1, -2)           # stacked terms (r, ..., m, n)
-        return ops.polar_update(x, t, a, mhat)
+        with jax.named_scope("combine"):
+            t = jnp.swapaxes(w, -1, -2)       # stacked terms (r, ..., m, n)
+            return ops.polar_update(x, t, a, mhat)
     if mode == "cholqr2":
         # the QR-form terms fold the a_j weights into their sum, so the
         # combine sees one pre-summed term with unit weight
@@ -342,7 +356,8 @@ def zolo_iteration(x, c_odd, a, mhat, *, mode: str = "chol",
     else:
         _validate_iter_mode("mode", mode)
     one = jnp.ones((1,), jnp.promote_types(x.dtype, jnp.float32))
-    return ops.polar_update(x, t[None], one, mhat)
+    with jax.named_scope("combine"):
+        return ops.polar_update(x, t[None], one, mhat)
 
 
 def run_schedule(x, c_odd, a_wts, mhats, *, qr_mode: str = "cholqr2",

@@ -46,6 +46,13 @@ import jax.numpy as jnp
 from repro.core import zolo as _zolo
 
 
+@jax.named_scope("psum")
+def _psum(x, axis: str):
+    """``jax.lax.psum`` under the name scope ``psum``, which every
+    collective of the grouped bundles carries."""
+    return jax.lax.psum(x, axis)
+
+
 def sep_reduce_ops(base: Optional[_zolo.ZoloOps] = None,
                    *, axis: str = "sep") -> _zolo.ZoloOps:
     """A ZoloOps bundle whose ``gram`` (and ``fnorm``) all-reduce over
@@ -68,7 +75,7 @@ def sep_reduce_ops(base: Optional[_zolo.ZoloOps] = None,
 
     def gram(x, c=0.0):
         if isinstance(c, (int, float)) and c == 0.0:
-            return jax.lax.psum(base.gram(x, 0.0), axis)
+            return _psum(base.gram(x, 0.0), axis)
         # fused sep-psum shifted Gram: one-hot the shift onto the
         # axis-0 shard's partial product so the psum output IS
         # G + c I — no replicated post-psum +cI epilogue serializing
@@ -78,13 +85,13 @@ def sep_reduce_ops(base: Optional[_zolo.ZoloOps] = None,
         # carries c.
         c_arr = jnp.asarray(c)
         w = (jax.lax.axis_index(axis) == 0).astype(c_arr.dtype)
-        return jax.lax.psum(base.gram(x, w * c_arr), axis)
+        return _psum(base.gram(x, w * c_arr), axis)
 
     def fnorm(x):
         # global Frobenius norm of the row-sharded iterate: local sum of
         # squares + one psum.  (Over "zolo" the iterate is replicated —
         # every group computes the identical value, no reduction.)
-        return jnp.sqrt(jax.lax.psum(jnp.sum(jnp.abs(x) ** 2), axis))
+        return jnp.sqrt(_psum(jnp.sum(jnp.abs(x) ** 2), axis))
 
     def fnorm_pair(a, b):
         # both residual-rule norms in ONE all-reduce: stack the two
@@ -93,7 +100,7 @@ def sep_reduce_ops(base: Optional[_zolo.ZoloOps] = None,
         # dynamic iteration)
         loc = jnp.stack([jnp.sum(jnp.abs(a) ** 2),
                          jnp.sum(jnp.abs(b) ** 2)])
-        return jnp.sqrt(jax.lax.psum(loc, axis))
+        return jnp.sqrt(_psum(loc, axis))
 
     return base._replace(gram=gram, fnorm=fnorm, fnorm_pair=fnorm_pair)
 
@@ -129,7 +136,7 @@ def zolo_term_group_ops(base: Optional[_zolo.ZoloOps] = None,
     def polar_update(x, t, a, mhat):
         y = _kops.grouped_combine(x, t, a, mhat, xw,
                                   use_pallas=combine_kernel)
-        return jax.lax.psum(y, axis)
+        return _psum(y, axis)
 
     def coeff_select(c_odd, a):
         j = jax.lax.axis_index(axis)

@@ -516,6 +516,7 @@ class SvdPlan:
     # --- traceable implementations (shared with the back-compat
     #     wrappers in repro.core.svd, which call them uncompiled) ------
 
+    @jax.named_scope("prescale")
     def _prescale(self, x):
         if self.config.scale == "power":
             # sharp 1.05x power-iteration bound (the ZoloMuon setting)
@@ -587,26 +588,28 @@ class SvdPlan:
             h = jax.lax.with_sharding_constraint(
                 h, jax.sharding.NamedSharding(self.mesh,
                                               jax.sharding.PartitionSpec()))
-        w, v = self._eig_spec.fn(h, **self._eig_kwargs)
-        # HIGHEST: U is a result, and at TPU DEFAULT precision an f32
-        # product runs as one bf16 pass (~2e-3 relative)
-        u = jnp.einsum("...mk,...kn->...mn", q, v,
-                       precision=jax.lax.Precision.HIGHEST)
-        # ascending -> descending; fold any tiny negative eigenvalue's
-        # sign into U so that s >= 0.
-        sign = jnp.where(w < 0, -1.0, 1.0).astype(u.dtype)
-        s = jnp.abs(w)
-        if alpha is not None:
-            s = s * alpha.astype(s.dtype)
-        u = u * sign[..., None, :]
-        order = jnp.argsort(-s, axis=-1)
-        s = jnp.take_along_axis(s, order, axis=-1)
-        u = jnp.take_along_axis(u, order[..., None, :], axis=-1)
-        v = jnp.take_along_axis(v, order[..., None, :], axis=-1)
-        vh = jnp.swapaxes(v, -1, -2)
-        u = u.astype(out_dtype)
-        s = s.astype(out_dtype)
-        vh = vh.astype(out_dtype)
+        with jax.named_scope("eig"):
+            w, v = self._eig_spec.fn(h, **self._eig_kwargs)
+        with jax.named_scope("u_qv"):
+            # HIGHEST: U is a result, and at TPU DEFAULT precision an f32
+            # product runs as one bf16 pass (~2e-3 relative)
+            u = jnp.einsum("...mk,...kn->...mn", q, v,
+                           precision=jax.lax.Precision.HIGHEST)
+            # ascending -> descending; fold any tiny negative eigenvalue's
+            # sign into U so that s >= 0.
+            sign = jnp.where(w < 0, -1.0, 1.0).astype(u.dtype)
+            s = jnp.abs(w)
+            if alpha is not None:
+                s = s * alpha.astype(s.dtype)
+            u = u * sign[..., None, :]
+            order = jnp.argsort(-s, axis=-1)
+            s = jnp.take_along_axis(s, order, axis=-1)
+            u = jnp.take_along_axis(u, order[..., None, :], axis=-1)
+            v = jnp.take_along_axis(v, order[..., None, :], axis=-1)
+            vh = jnp.swapaxes(v, -1, -2)
+            u = u.astype(out_dtype)
+            s = s.astype(out_dtype)
+            vh = vh.astype(out_dtype)
         if transposed:
             # a = (u s vh)^T = v s u^T
             return vh.swapaxes(-1, -2), s, jnp.swapaxes(u, -1, -2), info

@@ -2,6 +2,8 @@
 
 Runs in subprocesses so the main test process keeps 1 device."""
 
+import pytest
+
 from conftest import run_multidevice_script
 
 _SCRIPT = r"""
@@ -309,3 +311,35 @@ print("DYN_PLAN_OK")
 
 def test_grouped_dynamic_plan_subprocess():
     run_multidevice_script(_DYN_PLAN_SCRIPT, "DYN_PLAN_OK")
+
+
+# The grouped plan's collectives carry the name scope ``psum`` (the
+# all-reduces' op_name metadata in the compiled module), which a
+# profiler's name-scope view reads.
+_PSUM_SCOPE_SCRIPT = r"""
+import re
+import jax, jax.numpy as jnp
+import repro.solver as S
+from repro.dist import zolo_group_mesh
+
+n = 128
+p = S.plan(S.SvdConfig(kappa=100.0, l0_policy="estimate_at_plan"), (n, n),
+           "float32", mesh=zolo_group_mesh(2))
+assert p.mode == "grouped" and p.sep == 4, p
+entry = {"polar": p.polar, "svd": p.svd}[ENTRY]
+text = jax.jit(entry).lower(jax.ShapeDtypeStruct((n, n), jnp.float32)) \
+    .compile().as_text()
+paths = [re.search(r'op_name="([^"]*)"', line).group(1)
+         for line in text.splitlines()
+         if re.search(r" all-reduce(-start)?\(", line) and "op_name" in line]
+# the last component names the operation itself, the ones before it
+# are scopes
+assert any("psum" in path.split("/")[:-1] for path in paths), paths
+print("PSUM_SCOPE_OK")
+"""
+
+
+@pytest.mark.parametrize("entry", ["polar", "svd"])
+def test_grouped_plan_collectives_carry_psum_scope_subprocess(entry):
+    run_multidevice_script(f"ENTRY = {entry!r}\n" + _PSUM_SCOPE_SCRIPT,
+                           "PSUM_SCOPE_OK")
